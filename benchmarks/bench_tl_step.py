@@ -309,8 +309,8 @@ _PRODUCTION_SCRIPT = textwrap.dedent("""
     import json, os, time
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, numpy as np
-    jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     from repro.analysis.hlo_flops import analyze
     from repro.analysis.roofline import (HBM_BW, ICI_BW, PEAK_FLOPS)
     from repro.configs import get_config
@@ -413,18 +413,15 @@ _PRODUCTION_SCRIPT = textwrap.dedent("""
                                 "engine_clock": clocks}))
 """)
 
-# The elastic-recovery measurement runs in its OWN subprocess, with the
-# persistent compilation cache left OFF: the recovery path re-jits the same
-# step across shrinking meshes, and jax 0.4.37's CPU persistent-cache
-# serialization corrupts the heap on that pattern (glibc "corrupted
-# double-linked list" abort inside the first recovery re-jit when
-# jax_compilation_cache_dir is set; clean without it).  Keeping it separate
-# also means a crash here degrades to an `elastic_error` column instead of
-# taking the dryrun/engine-clock columns down with it.
+# The elastic-recovery measurement re-jits the step across shrinking
+# meshes; it runs in a process of its own so its forced devices and
+# re-jits never share a jax runtime with the columns above.
 _ELASTIC_SCRIPT = textwrap.dedent("""
     import dataclasses, json, os, tempfile, time
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, numpy as np
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     from repro.configs import get_config
     from repro.configs.base import InputShape
     from repro.data.pipeline import (VirtualBatchLoader, shard_corpus,
@@ -473,32 +470,28 @@ _ELASTIC_SCRIPT = textwrap.dedent("""
 """)
 
 
-def _run_result_script(script: str, error_key: str, timeout_s: int) -> dict:
-    """Run one measurement subprocess; degrade to an ``{error_key: ...}``
-    column on timeout/crash so the columns already computed this run still
-    reach the trajectory."""
-    env = dict(os.environ, PYTHONPATH=os.path.join(REPO_ROOT, "src"))
-    try:
-        proc = subprocess.run([sys.executable, "-c", script],
-                              env=env, capture_output=True, text=True,
-                              timeout=timeout_s)
-    except subprocess.TimeoutExpired:
-        return {error_key: f"subprocess timed out ({timeout_s}s)"}
+def _run_result_script(script: str, what: str, timeout_s: int) -> dict:
+    """Run one CPU-only measurement subprocess and parse its RESULT line.
+    The child pins ``JAX_PLATFORMS=cpu`` before jax loads, so it never
+    contends for an accelerator; a child that fails or times out fails the
+    benchmark."""
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO_ROOT, "src"),
+               JAX_PLATFORMS="cpu")
+    proc = subprocess.run([sys.executable, "-c", script],
+                          env=env, capture_output=True, text=True,
+                          timeout=timeout_s)
     if proc.returncode != 0:
-        return {error_key: proc.stderr[-2000:]}
+        raise RuntimeError(f"{what} subprocess failed "
+                           f"(exit {proc.returncode}):\n{proc.stderr[-2000:]}")
     line = [l for l in proc.stdout.splitlines() if l.startswith("RESULT")][0]
     return json.loads(line.split("RESULT ")[1])
 
 
 def _production_columns() -> dict:
     """Run the production-path measurements in subprocesses (the forced
-    8-device count must never leak into this process's jax; the elastic
-    drill additionally needs the persistent compilation cache off — see
-    ``_ELASTIC_SCRIPT``)."""
-    out = _run_result_script(_PRODUCTION_SCRIPT, "production_error", 1500)
-    out.update(_run_result_script(_ELASTIC_SCRIPT, "elastic_error", 900))
-    if "production_error" in out:
-        return out
+    8-device count must never leak into this process's jax)."""
+    out = _run_result_script(_PRODUCTION_SCRIPT, "production", 1500)
+    out.update(_run_result_script(_ELASTIC_SCRIPT, "elastic", 900))
     d = out["production_dryrun"]
     print(f"bench_tl_step/production_dryrun,"
           f"{d['step_time_s_cpu'] * 1e6:.0f},"

@@ -194,17 +194,9 @@ def _trip_count(cond: Computation, comps) -> int:
 _COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
                 "collective-permute")
 
-# a generic scatter that the backend expanded into a loop (XLA:CPU's
-# scatter expander) keeps the originating jaxpr primitive in its op_name
-# metadata: ".../scatter" (also scatter-add etc.); the leading boundary
-# keeps "reduce_scatter" collectives out
-_SCATTER_META_RE = re.compile(
-    r'op_name="(?:[^"]*/)?scatter(?:[-_][a-z]+)?(?:\[|")')
-
-
 def _max_tensor_bytes(shape_str: str) -> int:
-    """Largest single tensor in an HLO shape string — for a scatter-expander
-    while loop this is the scattered result buffer, not the loop carries."""
+    """Largest single tensor in an HLO shape string — for a variadic
+    scatter's tuple result, its largest scattered buffer."""
     best = 0
     for m in _SHAPE_RE.finditer(shape_str):
         dt, dims = m.group(1), m.group(2)
@@ -344,11 +336,9 @@ def analyze(text: str) -> Costs:
                     if key in attrs:
                         out.add(cost_of(attrs[key]))
 
-            root = op
-            if op == "fusion" and "calls" in attrs:
-                root = _fusion_root_op(attrs["calls"], comps)
-            if root == "scatter" or (op == "while"
-                                     and _SCATTER_META_RE.search(ins.rest)):
+            # a scatter inside a fusion is counted where it sits: the
+            # fused computation is walked through its ``calls`` above
+            if op == "scatter":
                 out.n_scatter += 1
                 out.scatter_bytes += _max_tensor_bytes(ins.shape)
 

@@ -77,15 +77,13 @@ def _make_row_permuter(mesh: Optional[Mesh], strategy: str) -> Callable:
     if n_dp <= 1:
         return permute
 
-    from jax.experimental.shard_map import shard_map
-
     def sharded(perm, *tensors):
         B = perm.shape[0]
         if B % n_dp != 0 or B < n_dp:
             return permute(perm, *tensors)
         specs = tuple(P(dp, *([None] * (t.ndim - 1))) for t in tensors)
-        return shard_map(permute, mesh=mesh, in_specs=(P(dp),) + specs,
-                         out_specs=specs, check_rep=False)(perm, *tensors)
+        return jax.shard_map(permute, mesh=mesh, in_specs=(P(dp),) + specs,
+                             out_specs=specs, check_vma=False)(perm, *tensors)
 
     return sharded
 
@@ -146,8 +144,13 @@ def tl_loss_fn(model: Model, cfg: ModelConfig, remat_mode: str = "tl",
                 rows["tokens"] = tokens
             if mask is not None:
                 rows["mask"] = mask
-            rows = dict(zip(rows, permute_rows(batch["perm"],
-                                               *rows.values())))
+            # the barriers fence the reassembly off from its neighbours so
+            # XLA compiles both phases alike under either lowering, and the
+            # strategies give the same floats (on the TPU a generic scatter
+            # fused into its consumers moved the loss by a few ulps)
+            fence = jax.lax.optimization_barrier
+            rows = dict(zip(rows, fence(permute_rows(
+                batch["perm"], *fence(tuple(rows.values()))))))
             h1, targets = rows["h1"], rows["targets"]
             tokens = rows.get("tokens", tokens)
             mask = rows.get("mask", mask)

@@ -23,9 +23,10 @@ Packages:
 
 Interpret mode is resolved process-wide by :func:`resolve_interpret`: the
 ``REPRO_PALLAS_INTERPRET`` env var (``1``/``0``) overrides, else kernels
-interpret on CPU backends and lower for real on TPU hosts — so one test
-suite drives both (CI sets nothing and interprets; a TPU host exports
-``REPRO_PALLAS_INTERPRET=0`` to exercise Mosaic lowering).
+interpret on CPU backends and lower for real on TPU hosts.  The tests run
+interpreted on the CPU; ``tests/test_tpu_compile.py`` compiles the
+main-path kernels for a described v5e, and ``chip_smoke.py`` runs them on
+the chip (refusing to start if they would be interpreted).
 """
 import os
 

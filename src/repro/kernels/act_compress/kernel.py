@@ -9,7 +9,7 @@ plus one f32 scale per row.
 Quantizer formulation (shared by both rungs, and load-bearing for the
 error-feedback lane in ``repro.core.transport``):
 
-    scale = max(absmax(row), eps)
+    scale = absmax(row)   (1 for an all-zero row)
     q     = round(x / scale * DENOM)        # int8: clip to ±127; fp8: cast
     x'    = q / DENOM * scale
 
@@ -19,11 +19,16 @@ dequant time.  A spatially-constant row then round-trips **bit-exactly**:
 ``x' == x`` and the error-feedback residual of a constant tensor is
 *exactly zero* — the lossless-in-the-limit property the transport's EF
 accumulator tests pin.  (The historical ``scale = absmax/127`` form fails
-this: ``fl(127 · fl(c/127)) != c`` in general.)
+this: ``fl(127 · fl(c/127)) != c`` in general.)  The scale is never
+clamped up to an epsilon: a row whose absmax is tiny or subnormal would
+then quantize to zero instead of to its rails.  Only an all-zero row needs
+a stand-in scale, and any positive one dequantizes its zeros exactly.
 
 Grid: row blocks.  BlockSpec tile (BR, D) f32 in, (BR, D) int8|fp8 +
-(BR,) f32 out — e.g. BR=256, D=8192 → 8 MB in-tile, within VMEM for one
-buffer; use BR=128 for d_model=8192 models to leave double-buffer headroom.
+(BR, 1) f32 scales out — a column block whose minor dim is the whole
+array dim, which Mosaic tiles (a 1-D ``(BR,)`` block it refuses).  E.g.
+BR=256, D=8192 → 8 MB in-tile, within VMEM for one buffer; use BR=128 for
+d_model=8192 models to leave double-buffer headroom.
 """
 from __future__ import annotations
 
@@ -33,19 +38,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 # codec -> (wire dtype, dequant denominator).  Exactness at the rails
-# (q = ±DENOM → ±1.0) is enforced by ``_pin_rails`` in the dequant — XLA
-# may rewrite division by a constant into multiplication by its rounded
-# reciprocal or reassociate ``q/DENOM*scale``, either of which is an ulp
-# off at the rails.  fp8 uses e4m3fn with a power-of-two denominator on
-# top of that: 256 <= 448 (e4m3 max normal) so there is no overflow,
-# ±256 is exactly representable, q/256 is exact under *any* rewrite, and
-# e4m3's ~2^-4 relative precision is unchanged by which slice of the
-# exponent range we use.  int8 keeps the conventional 127 (the
-# absmax/127 error bound is pinned by tests).
+# (q = ±DENOM ↔ |x| = absmax) is enforced by selects, not arithmetic — see
+# ``quantize_levels`` / ``dequantize_levels``.  fp8 uses e4m3fn with a
+# power-of-two denominator: 256 <= 448 (e4m3 max normal) so there is no
+# overflow, ±256 is exactly representable, and e4m3's ~2^-4 relative
+# precision is unchanged by which slice of the exponent range we use.
+# int8 keeps the conventional 127 (the absmax/127 error bound is pinned by
+# tests).
 CODECS = {
     "int8": (jnp.int8, 127.0),
     "fp8": (jnp.float8_e4m3fn, 256.0),
 }
+
+_MIN_NORMAL_BITS = 0x00800000          # int32 bits of the smallest normal f32
 
 
 def _check_codec(codec: str):
@@ -55,32 +60,61 @@ def _check_codec(codec: str):
     return CODECS[codec]
 
 
-def _pin_rails(qf, u, denom):
-    """Force the rail levels ``q == ±DENOM`` to dequantize to exactly
-    ``±1.0``.  XLA is free to rewrite ``q / DENOM * scale`` into
-    ``q · fl(1/DENOM) · scale`` or ``q · (scale/DENOM)``, either of which
-    is off by an ulp at the rails — and the rails are exactly where the
-    error-feedback exactness argument lives (a constant row quantizes to
-    all-rails and must round-trip bit-equal, so its residual is exactly
-    zero).  Interior levels only need the bounded-error property, which
-    any rewrite preserves."""
-    return jnp.where(jnp.abs(qf) == denom, jnp.sign(qf), u)
+def _bits(x):
+    return jax.lax.bitcast_convert_type(x, jnp.int32)
+
+
+def _magnitude_bits(x):
+    """``|x|`` as int32 bits, which order like the magnitudes themselves —
+    so a max over them is exact even where a flushing ALU would zero a
+    subnormal float max."""
+    return _bits(x) & 0x7FFFFFFF
+
+
+def row_scale(x):
+    """Per-row absmax as an (R, 1) column; an all-zero row gets 1."""
+    top = jnp.max(_magnitude_bits(x), axis=-1, keepdims=True)
+    absmax = jax.lax.bitcast_convert_type(top, jnp.float32)
+    return jnp.where(top == 0, 1.0, absmax)
+
+
+def quantize_levels(x, scale, codec: str):
+    """f32 rows -> wire levels.  Elements with ``|x| == scale`` (compared
+    bitwise) go straight to the rails ``±DENOM`` — the exactness the EF
+    lane needs must not rest on ``x / scale`` rounding to 1, because XLA:CPU
+    and the TPU flush subnormals: a row whose absmax is subnormal would
+    otherwise divide 0/0.  Such a row's other elements are zero to a
+    flushing ALU, and quantize to 0."""
+    qdtype, denom = _check_codec(codec)
+    normal = _bits(scale) >= _MIN_NORMAL_BITS
+    u = x / jnp.where(normal, scale, 1.0) * denom
+    rail = jnp.where(_bits(x) < 0, -denom, denom)
+    u = jnp.where(_magnitude_bits(x) == _bits(scale), rail, u)
+    if codec == "int8":
+        return jnp.clip(jnp.round(u), -127, 127).astype(qdtype)
+    # e4m3 cast rounds to nearest; |u| <= 256 < 448 max normal
+    return u.astype(qdtype)
+
+
+def dequantize_levels(qf, scale, denom):
+    """Wire levels (as f32) -> values.  The rails ``q == ±DENOM`` select
+    ``±scale`` itself, bit-exact: XLA is free to rewrite ``q / DENOM *
+    scale`` into ``q · fl(1/DENOM) · scale`` or ``q · (scale/DENOM)``,
+    either of which is an ulp off at the rails — and the rails are exactly
+    where the error-feedback exactness argument lives (a constant row
+    quantizes to all-rails and must round-trip bit-equal, so its residual is
+    exactly zero).  Interior levels only need the bounded-error property,
+    which any rewrite preserves."""
+    interior = qf / denom * scale
+    return jnp.where(jnp.abs(qf) == denom,
+                     jnp.where(qf < 0, -scale, scale), interior)
 
 
 def _make_quant_kernel(codec: str):
-    qdtype, denom = _check_codec(codec)
-
     def _quant_kernel(x_ref, q_ref, s_ref):
         x = x_ref[...].astype(jnp.float32)
-        absmax = jnp.max(jnp.abs(x), axis=-1)
-        scale = jnp.maximum(absmax, 1e-12)
-        u = x / scale[:, None] * denom
-        if codec == "int8":
-            q = jnp.clip(jnp.round(u), -127, 127).astype(qdtype)
-        else:
-            # e4m3 cast rounds to nearest; |u| <= 256 < 448 max normal
-            q = u.astype(qdtype)
-        q_ref[...] = q
+        scale = row_scale(x)
+        q_ref[...] = quantize_levels(x, scale, codec)
         s_ref[...] = scale
 
     return _quant_kernel
@@ -91,8 +125,8 @@ def _make_dequant_kernel(codec: str):
 
     def _dequant_kernel(q_ref, s_ref, x_ref):
         qf = q_ref[...].astype(jnp.float32)
-        u = _pin_rails(qf, qf / denom, denom)
-        x_ref[...] = (u * s_ref[...][:, None]).astype(x_ref.dtype)
+        x_ref[...] = dequantize_levels(qf, s_ref[...], denom).astype(
+            x_ref.dtype)
 
     return _dequant_kernel
 
@@ -106,20 +140,21 @@ def quantize_rows(x, *, codec: str = "int8", block_rows: int = 128,
     R, D = x.shape
     assert R % block_rows == 0
     grid = (R // block_rows,)
-    return pl.pallas_call(
+    q, scales = pl.pallas_call(
         _make_quant_kernel(codec),
         grid=grid,
         in_specs=[pl.BlockSpec((block_rows, D), lambda i: (i, 0))],
         out_specs=[
             pl.BlockSpec((block_rows, D), lambda i: (i, 0)),
-            pl.BlockSpec((block_rows,), lambda i: (i,)),
+            pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((R, D), qdtype),
-            jax.ShapeDtypeStruct((R,), jnp.float32),
+            jax.ShapeDtypeStruct((R, 1), jnp.float32),
         ],
         interpret=interpret,
     )(x)
+    return q, scales[:, 0]
 
 
 def dequantize_rows(q, scales, *, codec: str = "int8", out_dtype=jnp.float32,
@@ -136,9 +171,9 @@ def dequantize_rows(q, scales, *, codec: str = "int8", out_dtype=jnp.float32,
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_rows, D), lambda i: (i, 0)),
-            pl.BlockSpec((block_rows,), lambda i: (i,)),
+            pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((block_rows, D), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((R, D), out_dtype),
         interpret=interpret,
-    )(q, scales)
+    )(q, scales[:, None])

@@ -93,7 +93,9 @@ def ef_compress(x, residual, *, codec: str = "int8", block_rows: int = 128,
     in f32; ``delivered`` is cast back to ``x.dtype``."""
     xe = x.astype(jnp.float32)
     if residual is not None:
-        xe = xe + residual
+        # x + 0 must stay x bit-for-bit; a flushing ALU (XLA:CPU, TPU)
+        # would zero a subnormal x in the add
+        xe = jnp.where(residual == 0, xe, xe + residual)
     payload = compress(xe, codec=codec, block_rows=block_rows,
                        interpret=interpret)
     delivered = decompress(payload, xe.shape, out_dtype=jnp.float32,

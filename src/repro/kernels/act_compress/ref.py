@@ -6,24 +6,17 @@ at dequant time) — see ``kernel.py`` for why that form, not
 """
 import jax.numpy as jnp
 
-from repro.kernels.act_compress.kernel import CODECS, _pin_rails
+from repro.kernels.act_compress.kernel import (CODECS, dequantize_levels,
+                                               quantize_levels, row_scale)
 
 
 def quantize_rows_ref(x, codec: str = "int8"):
-    qdtype, denom = CODECS[codec]
     x = x.astype(jnp.float32)
-    absmax = jnp.max(jnp.abs(x), axis=-1)
-    scale = jnp.maximum(absmax, 1e-12)
-    u = x / scale[:, None] * denom
-    if codec == "int8":
-        q = jnp.clip(jnp.round(u), -127, 127).astype(qdtype)
-    else:
-        q = u.astype(qdtype)
-    return q, scale
+    scale = row_scale(x)
+    return quantize_levels(x, scale, codec), scale[:, 0]
 
 
 def dequantize_rows_ref(q, scale, out_dtype=jnp.float32, codec: str = "int8"):
     _, denom = CODECS[codec]
-    qf = q.astype(jnp.float32)
-    u = _pin_rails(qf, qf / denom, denom)
-    return (u * scale[:, None]).astype(out_dtype)
+    return dequantize_levels(q.astype(jnp.float32), scale[:, None],
+                             denom).astype(out_dtype)

@@ -22,6 +22,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -99,18 +100,9 @@ def flash_attention_bh(q, k, v, *, scale: float, causal: bool = True,
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, Sq, D), q.dtype),
         scratch_shapes=[
-            _scratch((block_q,), jnp.float32),
-            _scratch((block_q,), jnp.float32),
-            _scratch((block_q, D), jnp.float32),
+            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, D), jnp.float32),
         ],
         interpret=interpret,
     )(q, k, v)
-
-
-def _scratch(shape, dtype):
-    from jax.experimental import pallas as pl  # local alias
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        return pltpu.VMEM(shape, dtype)
-    except Exception:  # pragma: no cover - fallback for CPU interpret mode
-        return pl.VMEM(shape, dtype)

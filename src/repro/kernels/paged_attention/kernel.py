@@ -1,8 +1,9 @@
 """Paged-attention decode Pallas TPU kernel.
 
 Single-token decode against a block-table **paged KV cache**: physical pages
-of ``page_size`` tokens live in a shared pool ``(num_pages, page, KV, d)``
-and each sequence owns an ordered list of page indices (its *block table*).
+of ``page_size`` tokens live in a shared head-major pool
+``(num_pages, KV, page, d)`` and each sequence owns an ordered list of page
+indices (its *block table*).
 The kernel reuses the scalar-prefetched index-map routing proven in
 ``kernels/vb_scatter``: block tables and sequence lengths ride
 ``PrefetchScalarGridSpec`` so the K/V BlockSpec index maps dereference
@@ -21,9 +22,17 @@ leading ``v_width`` lanes of the key block (the cache stores one fused
 ``c_kv ‖ k_rope`` pool; values are the latent prefix), so MLA decode reads
 each page once.
 
+Tiling (v5e): the pool is head-major so one grid step's K/V block is one
+head's page ``(page, d)`` — the block's two minor dims are whole array
+dims, which Mosaic accepts for any page size and head width.  (A
+token-major ``(P, page, KV, d)`` pool would need a 1-row block in the
+second-minor KV dim, which Mosaic refuses.)  The running softmax
+statistics are ``(rep, 1)`` columns for the same reason.
+
 VMEM per grid step: q tile ``(rep, d)``, one K page ``(page, d)`` (+V for
 GQA), acc ``(rep, dv)`` f32 — ≲0.2 MB at page=16, d≤256: far under v5e's
-~16 MB, with headroom for double buffering.
+~16 MB, with headroom for double buffering.  Both matmuls run at
+``Precision.HIGHEST`` so an f32 pool gives f32 attention on the MXU.
 """
 from __future__ import annotations
 
@@ -37,6 +46,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import resolve_interpret
 
 NEG_INF = -1e30
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _paged_decode_kernel(bt_ref, len_ref, *refs, page_size: int, scale: float,
@@ -63,9 +73,10 @@ def _paged_decode_kernel(bt_ref, len_ref, *refs, page_size: int, scale: float,
     @pl.when(j * page_size < length)
     def _page():
         q = q_ref[0, 0].astype(jnp.float32)              # (rep, d)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)        # (page, d)
+        k = k_ref[0, 0].astype(jnp.float32)              # (page, d)
         rep = q.shape[0]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                precision=HIGHEST,
                                 preferred_element_type=jnp.float32) * scale
         k_pos = j * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (rep, page_size), 1)
@@ -74,27 +85,21 @@ def _paged_decode_kernel(bt_ref, len_ref, *refs, page_size: int, scale: float,
             mask &= k_pos > length - 1 - window
         s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_scr[...]                              # (rep, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + p.sum(axis=-1)
-        v = k[:, :v_width] if v_width else v_ref[0, :, 0, :].astype(jnp.float32)
-        acc_scr[...] = acc_scr[...] * corr[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        l_scr[...] = l_scr[...] * corr + p.sum(axis=-1, keepdims=True)
+        v = k[:, :v_width] if v_width else v_ref[0, 0].astype(jnp.float32)
+        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())), precision=HIGHEST,
+            preferred_element_type=jnp.float32)
         m_scr[...] = m_new
 
     @pl.when(j == nj - 1)
     def _finish():
         denom = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, 0] = (acc_scr[...] / denom[:, None]).astype(o_ref.dtype)
-
-
-def _scratch(shape, dtype):
-    try:
-        return pltpu.VMEM(shape, dtype)
-    except Exception:  # pragma: no cover - fallback for CPU interpret mode
-        return pl.VMEM(shape, dtype)
+        o_ref[0, 0] = (acc_scr[...] / denom).astype(o_ref.dtype)
 
 
 def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
@@ -103,8 +108,8 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
     """One decode step of every sequence against its paged KV cache.
 
     q:            (B, H, d)        — this step's query (token at lengths-1)
-    k_pages:      (P, page, KV, d) — shared physical page pool
-    v_pages:      (P, page, KV, dv) or None when ``v_width`` routes V out of
+    k_pages:      (P, KV, page, d) — shared head-major physical page pool
+    v_pages:      (P, KV, page, dv) or None when ``v_width`` routes V out of
                   the key pool (MLA fused layout)
     block_tables: (B, max_pages) int32 — page j of seq b is k_pages[bt[b,j]];
                   slots beyond the sequence's pages must point at page 0
@@ -114,7 +119,7 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
     """
     interpret = resolve_interpret(interpret)
     B, H, d = q.shape
-    num_pages, page_size, KV, _ = k_pages.shape
+    num_pages, KV, page_size, _ = k_pages.shape
     rep = H // KV
     max_pages = block_tables.shape[1]
     dv = v_width if v_width else v_pages.shape[-1]
@@ -124,15 +129,15 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
 
     q_spec = pl.BlockSpec((1, 1, rep, d),
                           lambda b, h, j, bt, ln: (b, h, 0, 0))
-    k_spec = pl.BlockSpec((1, page_size, 1, d),
-                          lambda b, h, j, bt, ln: (bt[b, j], 0, h, 0))
+    k_spec = pl.BlockSpec((1, 1, page_size, d),
+                          lambda b, h, j, bt, ln: (bt[b, j], h, 0, 0))
     o_spec = pl.BlockSpec((1, 1, rep, dv),
                           lambda b, h, j, bt, ln: (b, h, 0, 0))
     in_specs = [q_spec, k_spec]
     operands = [qg, k_pages]
     if not v_width:
-        in_specs.append(pl.BlockSpec((1, page_size, 1, dv),
-                                     lambda b, h, j, bt, ln: (bt[b, j], 0, h, 0)))
+        in_specs.append(pl.BlockSpec((1, 1, page_size, dv),
+                                     lambda b, h, j, bt, ln: (bt[b, j], h, 0, 0)))
         operands.append(v_pages)
 
     kernel = functools.partial(_paged_decode_kernel, page_size=page_size,
@@ -143,9 +148,9 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
         in_specs=in_specs,
         out_specs=o_spec,
         scratch_shapes=[
-            _scratch((rep,), jnp.float32),
-            _scratch((rep,), jnp.float32),
-            _scratch((rep, dv), jnp.float32),
+            pltpu.VMEM((rep, 1), jnp.float32),
+            pltpu.VMEM((rep, 1), jnp.float32),
+            pltpu.VMEM((rep, dv), jnp.float32),
         ],
     )
     out = pl.pallas_call(

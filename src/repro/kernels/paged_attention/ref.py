@@ -21,16 +21,17 @@ def paged_decode_attention_ref(q, k_pages, v_pages, block_tables, lengths, *,
     """Pure-jnp reference with the same signature as the kernel wrapper."""
     del interpret
     B, H, d = q.shape
-    num_pages, page_size, KV, _ = k_pages.shape
+    num_pages, KV, page_size, _ = k_pages.shape
     rep = H // KV
     max_pages = block_tables.shape[1]
     L = max_pages * page_size
 
-    k = k_pages[block_tables].reshape(B, L, KV, d)       # (B, L, KV, d)
-    if v_width:
-        v = k[..., :v_width]
-    else:
-        v = v_pages[block_tables].reshape(B, L, KV, v_pages.shape[-1])
+    def gather(pool):                       # (P, KV, page, w) -> (B, L, KV, w)
+        g = pool[block_tables]                           # (B, maxp, KV, page, w)
+        return g.transpose(0, 1, 3, 2, 4).reshape(B, L, KV, pool.shape[-1])
+
+    k = gather(k_pages)
+    v = k[..., :v_width] if v_width else gather(v_pages)
 
     k_pos = jnp.arange(L, dtype=jnp.int32)
     valid = k_pos[None, :] < lengths[:, None]            # (B, L)

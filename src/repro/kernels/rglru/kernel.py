@@ -17,6 +17,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _rglru_kernel(a_ref, b_ref, h_ref, h_final_ref, state_scr, *, chunk: int):
@@ -67,15 +68,7 @@ def rglru_scan_b(a, b, *, chunk: int = 64, interpret=None):
             jax.ShapeDtypeStruct((B, S, W), a.dtype),
             jax.ShapeDtypeStruct((B, W), jnp.float32),
         ],
-        scratch_shapes=[_scratch((W,), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((W,), jnp.float32)],
         interpret=interpret,
     )(a, b)
     return h, hT
-
-
-def _scratch(shape, dtype):
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        return pltpu.VMEM(shape, dtype)
-    except Exception:  # pragma: no cover
-        return pl.VMEM(shape, dtype)

@@ -21,6 +21,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _ssd_kernel(dA_ref, x_ref, b_ref, c_ref, y_ref, hT_ref, h_scr, *,
@@ -101,15 +102,7 @@ def ssd_bh(dA, x, Bm, Cm, *, chunk: int = 256, interpret=None):
             jax.ShapeDtypeStruct((BH, S, P), x.dtype),
             jax.ShapeDtypeStruct((BH, P, N), jnp.float32),
         ],
-        scratch_shapes=[_scratch((P, N), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
         interpret=interpret,
     )(dA, x, Bm, Cm)
     return y, hT
-
-
-def _scratch(shape, dtype):
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        return pltpu.VMEM(shape, dtype)
-    except Exception:  # pragma: no cover
-        return pl.VMEM(shape, dtype)

@@ -10,30 +10,31 @@ payload tensor (x1, δ^(L), ∂L/∂X^(1)), before the tail vjp reads X^(1)
 back.  Because ``perm`` is a permutation, the zeros are dead: every
 destination row is written exactly once.
 
-This kernel streams each row exactly once instead.  ``perm`` is
-scalar-prefetched (``PrefetchScalarGridSpec``) so BlockSpec index maps can
-depend on it; the grid is ``(N, n_col_blocks)`` and grid step ``(i, j)``
-DMAs row ``i`` column-block ``j`` of every payload straight to its
-destination row — no zeros materialization, no full-batch VMEM residency,
-no scatter or sort ops in the lowering.  Two row routings share one body:
+This kernel copies each row exactly once instead, HBM to HBM by DMA.
+``perm`` is scalar-prefetched into SMEM; the kernel body issues one DMA per
+(row, tensor) from the source row to its destination row, then waits for
+all of them — no zeros materialization, no VMEM staging, no scatter or
+sort ops in the lowering.  Two row routings share one body:
 
 * ``scatter``: read row ``i``, write row ``perm[i]`` (the reassembly);
 * ``gather``:  read row ``idx[i]``, write row ``i`` (the reassembly's
   transpose — the custom-vjp backward gathers cotangents with the *same*
   ``perm``, no inverse permutation ever materializes).
 
-All payload tensors ride the same grid as a multi-ref call, so the whole
-reassembly is one kernel launch and one HBM pass over the payloads.
+All payload tensors ride one call, so the whole reassembly is one kernel
+launch and one HBM pass over the payloads.
 
-Tiling (v5e): blocks are ``(1, BLOCK_COLS)`` — VMEM holds
-``n_refs × 2 (in+out) × 2 (double-buffer) × BLOCK_COLS × 4 B`` ≈ 0.5 MB at
-the default 8192 columns, far under the 16 MB/core budget.  A tensor
-narrower than the widest ref collapses to fewer column blocks; its index
-map clamps ``j`` so the extra grid steps rewrite the last block
-idempotently (only hit when refs of very different widths share a call —
-the (N, C) δ^(L) next to a wide (N, D) X^(1)).
+Layout (v5e): a DMA moves whole (sublane, lane) tiles, and a bare row of a
+2-D array is a 1-sublane slice of its tiles.  So each ``(N, D)`` tensor is
+viewed as ``(N, D/128, 128)`` — a row is then a leading-dim slab of full
+tiles.  A row whose width is not a multiple of one native tile
+(8 sublanes × 128 lanes × the dtype's packing: 1024 f32, 2048 bf16
+elements) is zero-padded up to one and sliced back afterwards; the
+production widths (seq × d_model, seq) need no padding.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -42,20 +43,49 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import resolve_interpret
 
-BLOCK_COLS = 8192
+LANES = 128
 
 
-def _copy_rows_kernel(idx_ref, *refs):
-    # refs = (in_0..in_{n-1}, out_0..out_{n-1}); the row routing lives
-    # entirely in the BlockSpec index maps, so the body is a plain copy
-    del idx_ref
-    n = len(refs) // 2
-    for in_ref, out_ref in zip(refs[:n], refs[n:]):
-        out_ref[...] = in_ref[...]
+def _row_tile(dtype) -> int:
+    """Elements of one native (sublane, lane) tile: rows padded to a
+    multiple of this are whole-tile slabs for every dtype's packing."""
+    return 8 * LANES * max(1, 4 // jnp.dtype(dtype).itemsize)
 
 
-def permute_rows(idx, *tensors, mode: str = "scatter",
-                 block_cols: int = BLOCK_COLS, interpret=None):
+def _as_row_slabs(t):
+    n, width = t.shape
+    tile = _row_tile(t.dtype)
+    padded = -(-width // tile) * tile
+    if padded != width:
+        t = jnp.pad(t, ((0, 0), (0, padded - width)))
+    return t.reshape(n, padded // LANES, LANES)
+
+
+def _copy_rows_kernel(idx_ref, *refs, n_rows: int, mode: str):
+    # refs = (in_0..in_{n-1}, out_0..out_{n-1}, dma semaphores)
+    n = (len(refs) - 1) // 2
+    ins, outs, sems = refs[:n], refs[n:2 * n], refs[-1]
+
+    def copies(i):
+        src, dst = (i, idx_ref[i]) if mode == "scatter" else (idx_ref[i], i)
+        return [pltpu.make_async_copy(x.at[src], o.at[dst], sems.at[k])
+                for k, (x, o) in enumerate(zip(ins, outs))]
+
+    def start(i, carry):
+        for cp in copies(i):
+            cp.start()
+        return carry
+
+    def wait(i, carry):
+        for cp in copies(i):
+            cp.wait()
+        return carry
+
+    jax.lax.fori_loop(0, n_rows, start, 0)
+    jax.lax.fori_loop(0, n_rows, wait, 0)
+
+
+def permute_rows(idx, *tensors, mode: str = "scatter", interpret=None):
     """Route rows of every (N, D_t) tensor by ``idx`` in one fused pass.
 
     ``mode="scatter"``: ``out_t[idx[i]] = t[i]`` (``idx`` must be a
@@ -65,42 +95,29 @@ def permute_rows(idx, *tensors, mode: str = "scatter",
     scatter-by-permutation vjp pair.  Dtypes are per-ref (f32/bf16
     activations and int32 token rows mix freely).
     """
+    if mode not in ("scatter", "gather"):
+        raise ValueError(f"unknown row routing {mode!r}")
     interpret = resolve_interpret(interpret)
     n_rows = tensors[0].shape[0]
-    n_blocks = [-(-t.shape[1] // block_cols) for t in tensors]
-    grid_cols = max(n_blocks)
-
-    routed = lambda i, idx_ref: idx_ref[i]
-    direct = lambda i, idx_ref: i
-    in_row, out_row = ((direct, routed) if mode == "scatter"
-                       else (routed, direct))
-
-    def specs(row_of):
-        out = []
-        for t, nb in zip(tensors, n_blocks):
-            width = min(t.shape[1], block_cols)
-
-            def index_map(i, j, idx_ref, nb=nb, row_of=row_of):
-                return row_of(i, idx_ref), jnp.minimum(j, nb - 1)
-
-            out.append(pl.BlockSpec((1, width), index_map))
-        return out
-
+    slabs = [_as_row_slabs(t) for t in tensors]
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(n_rows, grid_cols),
-        in_specs=specs(in_row),
-        out_specs=specs(out_row),
+        grid=(1,),
+        in_specs=[hbm] * len(slabs),
+        out_specs=[hbm] * len(slabs),
+        scratch_shapes=[pltpu.SemaphoreType.DMA((len(slabs),))],
     )
-    return pl.pallas_call(
-        _copy_rows_kernel,
+    outs = pl.pallas_call(
+        functools.partial(_copy_rows_kernel, n_rows=n_rows, mode=mode),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct(t.shape, t.dtype) for t in tensors],
+        out_shape=[jax.ShapeDtypeStruct(s.shape, s.dtype) for s in slabs],
         interpret=interpret,
-    )(idx, *tensors)
+    )(idx.astype(jnp.int32), *slabs)
+    return [o.reshape(n_rows, -1)[:, :t.shape[1]]
+            for o, t in zip(outs, tensors)]
 
 
-def take_rows(idx, *tensors, block_cols: int = BLOCK_COLS, interpret=None):
+def take_rows(idx, *tensors, interpret=None):
     """``out_t[i] = t[idx[i]]`` — :func:`permute_rows` in gather mode."""
-    return permute_rows(idx, *tensors, mode="gather", block_cols=block_cols,
-                        interpret=interpret)
+    return permute_rows(idx, *tensors, mode="gather", interpret=interpret)

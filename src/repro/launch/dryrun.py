@@ -1,7 +1,9 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any other import (jax locks the device
-# count on first initialization).  Do not move them.
+os.environ["JAX_PLATFORMS"] = "cpu"
+# The lines above MUST run before any other import (jax locks the platform
+# and the device count on first initialization): the dry run lowers for 512
+# host devices and never takes an accelerator.  Do not move them.
 
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) and emit
 roofline artifacts.
@@ -25,13 +27,11 @@ from repro.analysis.roofline import Roofline, model_flops, summarize
 from repro.configs import get_config, get_shape
 from repro.core.tl_step import (make_serve_step, make_train_step,
                                 serve_shardings, train_shardings)
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.launch.specs import abstract_cache, abstract_params, input_specs
 from repro.models import build_model
 from repro.optim import adafactor
-
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
 
 
 def lower_one(arch: str, shape_name: str, mesh_kind: str, remat: str = "tl",
@@ -107,8 +107,6 @@ def lower_one(arch: str, shape_name: str, mesh_kind: str, remat: str = "tl",
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):       # jax<=0.4 returns [dict]
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
 
     # cost_analysis counts scan (while) bodies once; the HLO analyzer
@@ -170,6 +168,7 @@ def main():
     ap.add_argument("--no-serve-fsdp", action="store_true")
     ap.add_argument("--moe-ep", action="store_true")
     args = ap.parse_args()
+    use_compile_cache()
 
     try:
         art = lower_one(args.arch, args.shape, args.mesh, args.remat,

@@ -259,6 +259,7 @@ class Engine:
         self.params = None
         self.opt_state = None
         self._step_fn = None
+        self._state_shardings = None
         self._batch_shardings = None
         self._zero_embeds = None
         self._n_perm_shards = 1
@@ -340,6 +341,7 @@ class Engine:
                 self.params, self.opt_state, cfg, mesh, shape,
                 with_embeds=bool(cfg.frontend), with_perm=reassemble)
         donate = (0, 1) if self.donate else ()
+        self._state_shardings = in_sh[:2]
         self._step_fn = jax.jit(step, in_shardings=in_sh,
                                 out_shardings=out_sh, donate_argnums=donate)
         tok = tokens_pspec(mesh, shape.global_batch)
@@ -638,6 +640,11 @@ class Engine:
         losses = self._loss_acc
         params, opt_state = self.params, self.opt_state
         self.params = self.opt_state = None    # donated: drop stale refs
+        # commit the state to the step's shardings before the first step:
+        # fed fresh uncommitted arrays and then its own committed outputs,
+        # the step would compile twice
+        params, opt_state = jax.device_put((params, opt_state),
+                                           self._state_shardings)
         armed = self.device_faults is not None or self.elastic
         deadline = self.watchdog_s if (armed and self.watchdog_s
                                        and self.watchdog_s > 0) else None

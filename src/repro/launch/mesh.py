@@ -21,27 +21,23 @@ import jax
 import numpy as np
 
 
-def make_mesh_compat(shape, axes):
-    """``jax.make_mesh`` with Auto axis types when this jax supports them
-    (>= 0.5); plain mesh construction otherwise."""
-    try:
-        from jax.sharding import AxisType
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    except (ImportError, TypeError):
-        return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` over the visible devices with Auto (GSPMD-sharded)
+    axes on every mesh axis."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips when ``multi_pod``."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_debug_mesh(n_data: int = 2, n_model: int = 2):
     """Small mesh for CPU multi-device tests (host platform device count)."""
-    return make_mesh_compat((n_data, n_model), ("data", "model"))
+    return make_mesh((n_data, n_model), ("data", "model"))
 
 
 def make_host_mesh(n_model: int = None):
@@ -52,14 +48,14 @@ def make_host_mesh(n_model: int = None):
     n = jax.device_count()
     if n_model is None:
         n_model = 2 if n % 2 == 0 and n >= 2 else 1
-    return make_mesh_compat((n // n_model, n_model), ("data", "model"))
+    return make_mesh((n // n_model, n_model), ("data", "model"))
 
 
 def make_multipod_debug_mesh(pod: int = 2, data: int = 2, model: int = 2):
     """Smallest mesh carrying the full multi-pod axis set (pod, data, model);
     runnable on 8 forced host devices.  Exercises the composite (pod, data)
     batch axes of :func:`repro.dist.sharding.batch_axes` without 512 chips."""
-    return make_mesh_compat((pod, data, model), ("pod", "data", "model"))
+    return make_mesh((pod, data, model), ("pod", "data", "model"))
 
 
 def resolve_mesh(kind: str, *, multi_pod: bool = False):

@@ -26,8 +26,11 @@ def run_one(arch, shape, mesh, out, remat, tag, timeout, extra=()):
            "--tag", tag] + list(extra)
     t0 = time.time()
     try:
+        # the child is a CPU-only lowering: it must never contend for an
+        # accelerator this process (or a sibling) may hold
         proc = subprocess.run(cmd, capture_output=True, text=True,
-                              timeout=timeout)
+                              timeout=timeout,
+                              env=dict(os.environ, JAX_PLATFORMS="cpu"))
         ok = proc.returncode == 0
         err = proc.stderr[-2000:] if not ok else ""
     except subprocess.TimeoutExpired:
@@ -40,6 +43,8 @@ def run_one(arch, shape, mesh, out, remat, tag, timeout, extra=()):
     if os.path.exists(path):
         with open(path) as f:
             status = json.load(f).get("status", "?")
+    if not ok and status in ("ok", "skipped"):
+        status = "error"      # a crashed child beside a stale artifact
     print(f"[{dt:6.1f}s] {arch:22s} {shape:12s} {mesh:7s} -> {status}"
           + (f"  {err.splitlines()[-1] if err else ''}" if not ok else ""),
           flush=True)

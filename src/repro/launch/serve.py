@@ -95,6 +95,9 @@ def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="starcoder2-3b")
     ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--full", dest="reduced", action="store_false",
+                    help="the architecture's published widths instead of "
+                         "the reduced CPU preset")
     ap.add_argument("--engine", choices=["static", "continuous"],
                     default="static")
     ap.add_argument("--attention", choices=["paged", "dense"],

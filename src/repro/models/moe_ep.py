@@ -23,7 +23,6 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
@@ -125,12 +124,12 @@ def moe_apply_ep(params, cfg: ModelConfig, x, mesh: Mesh, *,
 
     dp = data_axis if len(data_axis) > 1 else data_axis[0]
     dspec = P(dp, model_axis if seq_shard > 1 else None, None)
-    out = shard_map(
+    out = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(dspec, P(), P(model_axis, None, None),
                   P(model_axis, None, None), P(model_axis, None, None)),
         out_specs=(dspec, P()),
-        check_rep=False,
+        check_vma=False,
     )(x, params["router"].astype(x.dtype), params["w_gate"], params["w_up"],
       params["w_down"])
     y, aux = out

@@ -17,9 +17,11 @@ Execution contract (what makes the engine oracle-equivalent, pinned by
   groups = batch rows), so co-batched sequences can never perturb each
   other's tokens — the property continuous batching needs.
 
-Page pools mirror the oracle cache pytree ({prefix, cycles, suffix}); MLA
-stores one fused ``c_kv ‖ k_rope`` pool per layer (values are the latent
-prefix, ``v_width`` in the kernel), keeping the MLA cache-memory saving.
+Page pools mirror the oracle cache pytree ({prefix, cycles, suffix}) and
+are head-major, ``(num_pages, KV, page, d)``, the layout the paged kernel
+tiles; MLA stores one fused ``c_kv ‖ k_rope`` pool per layer (one head;
+values are the latent prefix, ``v_width`` in the kernel), keeping the MLA
+cache-memory saving.
 
 Compiled callables are cached per ``(cfg.name, …)`` at module level —
 jax's own shape cache handles varying batch buckets and prompt lengths.
@@ -67,10 +69,10 @@ def _layer_pool(cfg: ModelConfig, num_pages: int, page_size: int, dtype):
     if cfg.attention == "mla":
         m = cfg.mla
         width = m.kv_lora_rank + m.qk_rope_head_dim
-        return {"kv": jnp.zeros((num_pages, page_size, 1, width), dtype)}
+        return {"kv": jnp.zeros((num_pages, 1, page_size, width), dtype)}
     KV, hd = cfg.n_kv_heads, cfg.resolved_head_dim
-    return {"k": jnp.zeros((num_pages, page_size, KV, hd), dtype),
-            "v": jnp.zeros((num_pages, page_size, KV, hd), dtype)}
+    return {"k": jnp.zeros((num_pages, KV, page_size, hd), dtype),
+            "v": jnp.zeros((num_pages, KV, page_size, hd), dtype)}
 
 
 def init_pages(cfg: ModelConfig, num_pages: int, page_size: int,
@@ -109,8 +111,7 @@ def _attn_decode(mp, cfg, page_size, xn, pool, tables, lengths, attn_fn,
         m = cfg.mla
         q_full, c_kv, k_rope = attention.mla_project(mp, cfg, xn, q_pos)
         val = jnp.concatenate([c_kv, k_rope], axis=-1)[:, 0]       # (B, width)
-        kv = pool["kv"].at[pidx, off].set(val[:, None, :].astype(
-            pool["kv"].dtype))
+        kv = pool["kv"].at[pidx, 0, off].set(val.astype(pool["kv"].dtype))
         scale = 1.0 / math.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
         out_lat = attn_fn(q_full[:, 0], kv, None, tables, n_valid,
                           scale=scale, v_width=m.kv_lora_rank,
@@ -120,8 +121,9 @@ def _attn_decode(mp, cfg, page_size, xn, pool, tables, lengths, attn_fn,
 
     H, hd = cfg.n_heads, cfg.resolved_head_dim
     q, k, v = attention.gqa_project(mp, cfg, xn, q_pos)
-    kp = pool["k"].at[pidx, off].set(k[:, 0].astype(pool["k"].dtype))
-    vp = pool["v"].at[pidx, off].set(v[:, 0].astype(pool["v"].dtype))
+    # advanced indices split by the head slice: the update is (B, KV, hd)
+    kp = pool["k"].at[pidx, :, off].set(k[:, 0].astype(pool["k"].dtype))
+    vp = pool["v"].at[pidx, :, off].set(v[:, 0].astype(pool["v"].dtype))
     out = attn_fn(q[:, 0], kp, vp, tables, n_valid,
                   scale=1.0 / math.sqrt(hd), interpret=interpret)
     out = jnp.einsum("bse,ed->bsd", out.reshape(B, 1, H * hd), mp["w_o"])
@@ -226,23 +228,21 @@ def make_prefill_fn(cfg: ModelConfig, *, page_size: int):
         pidx = table[pos // page_size]
         off = pos % page_size
 
+        def write(pool, rows):
+            # pool (num_pages, KV, page, w) <- rows (P, KV, w): advanced
+            # indices split by the head slice put the token axis first
+            return pool.at[pidx, :, off].set(rows.astype(pool.dtype))
+
         def copy(pool, cl, stacked):
             if cfg.attention == "mla":
                 val = jnp.concatenate([cl["c_kv"], cl["k_rope"]], axis=-1)
-                if stacked:                       # (n_cycles, 1, P, width)
-                    return {"kv": pool["kv"].at[:, pidx, off].set(
-                        val[:, 0][:, :, None, :].astype(pool["kv"].dtype))}
-                return {"kv": pool["kv"].at[pidx, off].set(
-                    val[0][:, None, :].astype(pool["kv"].dtype))}
-            if stacked:                           # (n_cycles, 1, P, KV, hd)
-                return {"k": pool["k"].at[:, pidx, off].set(
-                            cl["k"][:, 0].astype(pool["k"].dtype)),
-                        "v": pool["v"].at[:, pidx, off].set(
-                            cl["v"][:, 0].astype(pool["v"].dtype))}
-            return {"k": pool["k"].at[pidx, off].set(
-                        cl["k"][0].astype(pool["k"].dtype)),
-                    "v": pool["v"].at[pidx, off].set(
-                        cl["v"][0].astype(pool["v"].dtype))}
+                rows = {"kv": val[..., None, :]}  # one fused head
+            else:
+                rows = {"k": cl["k"], "v": cl["v"]}
+            if stacked:                       # (n_cycles, 1, P, KV, w)
+                return {n: jax.vmap(write)(pool[n], r[:, 0])
+                        for n, r in rows.items()}
+            return {n: write(pool[n], r[0]) for n, r in rows.items()}
 
         new_prefix = tuple(copy(pages["prefix"][i], cache["prefix"][i], False)
                            for i in range(len(plan.prefix)))

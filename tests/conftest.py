@@ -38,7 +38,7 @@ def pytest_collection_modifyitems(config, items):
 # Minimal deterministic `hypothesis` shim.
 #
 # The property tests use a small slice of the hypothesis API (given /
-# settings / strategies.{integers,floats,lists}).  When the real package is
+# settings / example / strategies.{integers,floats,lists}).  When the real package is
 # unavailable we install a seeded stand-in that draws `max_examples` random
 # examples per test, so the property tests still run (with fixed seeds)
 # instead of failing at collection.  If hypothesis is installed it wins.
@@ -72,6 +72,12 @@ except ImportError:
     def _tuples(*elems):
         return _Strategy(lambda r: tuple(e.draw(r) for e in elems))
 
+    def _example(**pinned):
+        def deco(fn):
+            fn._hyp_examples = [pinned] + getattr(fn, "_hyp_examples", [])
+            return fn
+        return deco
+
     def _settings(max_examples=20, deadline=None, **_kw):
         def deco(fn):
             fn._hyp_max_examples = max_examples
@@ -81,11 +87,14 @@ except ImportError:
     def _given(**strategies):
         def deco(fn):
             n = getattr(fn, "_hyp_max_examples", 20)
+            pinned = getattr(fn, "_hyp_examples", [])
 
             # no functools.wraps: pytest must see the (*args, **kwargs)
             # signature, not the original one (whose params would otherwise
             # be resolved as fixtures)
             def wrapper(*args, **kwargs):
+                for drawn in pinned:
+                    fn(*args, **kwargs, **drawn)
                 r = np.random.default_rng(0)
                 for _ in range(n):
                     drawn = {k: s.draw(r) for k, s in strategies.items()}
@@ -106,6 +115,7 @@ except ImportError:
 
     _hyp = types.ModuleType("hypothesis")
     _hyp.given = _given
+    _hyp.example = _example
     _hyp.settings = _settings
     _hyp.strategies = _st
     _hyp.__is_repro_stub__ = True

@@ -85,6 +85,11 @@ def test_test_jobs_pin_cpu_backend_and_jax_wheel(wf):
         assert setup["with"]["cache-dependency-path"] == "requirements-ci.txt"
     reqs = (ROOT / "requirements-ci.txt").read_text()
     assert "jax==" in reqs and "jaxlib==" in reqs
+    # one installation: CI pins exactly the jax/jaxlib this suite runs on
+    import jax
+    import jaxlib
+    assert f"jax=={jax.__version__}\n" in reqs
+    assert f"jaxlib=={jaxlib.__version__}\n" in reqs
 
 
 def test_nightly_leg_is_gated_and_runs_slow_tests(wf):

@@ -25,8 +25,8 @@ def test_activation_sharding_context_restores():
 
 def test_constraint_lowers_inside_jit():
     """with_sharding_constraint must trace under a (1-device) mesh."""
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((1,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("data",))
     with activation_sharding(("data",)):
         with mesh:
             out = jax.jit(lambda x: constrain_batch(x) * 2)(jnp.ones((2, 3)))

@@ -335,3 +335,43 @@ def test_engine_prefetch_is_double_buffered():
     for kind, _, _ in events:
         outstanding += 1 if kind == "put" else -1
         assert outstanding <= Engine.PREFETCH_DEPTH, events
+
+
+def test_engine_step_compiles_once():
+    """The first step sees freshly initialized (uncommitted) state, every
+    later step the step's own committed outputs.  The engine commits the
+    state to the step's shardings before the first step, so both hit one
+    executable: a second run (and every step after the first) compiles
+    nothing."""
+    import jax
+
+    from repro.configs import get_config
+    from repro.configs.base import InputShape
+    from repro.data.pipeline import (VirtualBatchLoader, shard_corpus,
+                                     synthetic_corpus)
+    from repro.launch.engine import Engine
+    from repro.launch.mesh import make_debug_mesh
+    from repro.models import build_model
+    from repro.optim import adamw
+
+    cfg = get_config("deepseek-7b", reduced=True)
+    docs = synthetic_corpus(4 * 16, 16, cfg.vocab_size, seed=1)
+    loader = VirtualBatchLoader(shard_corpus(docs, 4), 8, seed=0)
+    eng = Engine(build_model(cfg), cfg, adamw(3e-3, clip_norm=1.0),
+                 make_debug_mesh(1, 1), InputShape("t", 16, 8, "train"),
+                 pipeline=False, reassembly="pallas")
+    eng.init(jax.random.PRNGKey(0))
+    compiles = []
+
+    def on_event(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(duration)
+
+    eng.run(loader, steps=1)
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        res = eng.run(loader, steps=3)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+    assert res.steps == 3
+    assert compiles == []

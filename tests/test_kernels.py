@@ -9,7 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from hypothesis import given, settings, strategies as st
+from hypothesis import example, given, settings, strategies as st
 
 from repro.kernels.act_compress import (CODECS, compress, compressed_bytes,
                                         decompress, dequantize_rows_ref,
@@ -160,6 +160,8 @@ def test_bf16_roundtrip_regression():
        value=st.floats(-1e3, 1e3, allow_nan=False, width=32),
        rows=st.integers(1, 5), cols=st.integers(1, 16), sends=st.integers(1, 4))
 @settings(max_examples=25, deadline=None)
+@example(codec="fp8", value=3.3338291764751723e-41, rows=1, cols=1, sends=1)
+@example(codec="int8", value=-1e-13, rows=2, cols=3, sends=2)
 def test_ef_residual_of_constant_contracts_to_exact_zero(codec, value, rows,
                                                          cols, sends):
     """Lossless-in-the-limit, sharpest case: a constant tensor's
@@ -279,14 +281,20 @@ def test_vb_scatter_mixed_int_rows_ride_the_fused_pass():
 
 @pytest.mark.parametrize("mode", ["scatter", "gather"])
 def test_permute_rows_column_blocking(mode):
-    """Multi-column-block grid (narrow block_cols) and width-clamped narrow
-    refs produce the same rows as the unblocked oracle in both routings."""
+    """Rows spanning several native tiles (one exact multiple, one padded
+    past a tile boundary) and a narrow padded ref share one call and
+    produce the same rows as the oracle in both routings."""
     N = 7
     r = np.random.default_rng(5)
     idx = jnp.asarray(r.permutation(N).astype(np.int32))
-    wide = jnp.asarray(r.normal(size=(N, 20)).astype(np.float32))
+    wide = jnp.asarray(r.normal(size=(N, 2500)).astype(np.float32))
     narrow = jnp.asarray(r.normal(size=(N, 3)).astype(np.float32))
-    got_w, got_n = permute_rows(idx, wide, narrow, mode=mode, block_cols=8)
+    got_w, got_n = permute_rows(idx, wide, narrow, mode=mode)
+    exact = jnp.asarray(r.normal(size=(N, 2048)).astype(np.float32))
+    [got_e] = permute_rows(idx, exact, mode=mode)
+    want_e = (jnp.zeros_like(exact).at[idx].set(exact) if mode == "scatter"
+              else exact[idx])
+    np.testing.assert_array_equal(np.asarray(got_e), np.asarray(want_e))
     if mode == "scatter":
         want_w = jnp.zeros_like(wide).at[idx].set(wide)
         want_n = jnp.zeros_like(narrow).at[idx].set(narrow)

@@ -196,8 +196,8 @@ KERNEL_GRID = [
 def test_paged_kernel_matches_dense_ref(B, H, KV, d, page, maxp):
     rng = np.random.default_rng(B * 100 + H)
     P = B * maxp + 1
-    kp = jnp.asarray(rng.normal(size=(P, page, KV, d)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(P, page, KV, d)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(P, KV, page, d)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(P, KV, page, d)), jnp.float32)
     q = jnp.asarray(rng.normal(size=(B, H, d)), jnp.float32)
     # shuffled, non-contiguous block tables (page 0 kept as trash)
     bt = jnp.asarray(rng.permutation(np.arange(1, P))[:B * maxp]
@@ -221,7 +221,7 @@ def test_paged_kernel_mla_fused_pool():
     B, H, lora, rope, page, maxp = 3, 4, 32, 16, 4, 4
     d = lora + rope
     P = B * maxp + 1
-    kp = jnp.asarray(rng.normal(size=(P, page, 1, d)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(P, 1, page, d)), jnp.float32)
     q = jnp.asarray(rng.normal(size=(B, H, d)), jnp.float32)
     bt = jnp.asarray(rng.permutation(np.arange(1, P))[:B * maxp]
                      .reshape(B, maxp), jnp.int32)
@@ -241,8 +241,8 @@ def test_trash_page_contents_cannot_leak():
     rng = np.random.default_rng(9)
     B, H, KV, d, page, maxp = 2, 4, 2, 16, 4, 3
     P = B * maxp + 1
-    kp = jnp.asarray(rng.normal(size=(P, page, KV, d)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(P, page, KV, d)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(P, KV, page, d)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(P, KV, page, d)), jnp.float32)
     q = jnp.asarray(rng.normal(size=(B, H, d)), jnp.float32)
     bt = np.arange(1, 1 + B * maxp, dtype=np.int32).reshape(B, maxp)
     bt[:, -1] = TRASH_PAGE                        # tail slots -> trash
@@ -839,3 +839,25 @@ def test_cli_static_smoke(capsys):
         "--arch", "deepseek-7b", "--batch", "2", "--prompt-len", "4",
         "--gen", "3"])
     assert toks.shape == (2, 3)
+
+
+def test_cli_full_leaves_the_reduced_preset(monkeypatch, capsys):
+    """``--full`` asks for the published widths (``reduced=False``) and the
+    default keeps the CPU preset.  The spy still hands back the reduced
+    config so the drive stays CPU-sized; what is pinned is the flag."""
+    asked = []
+    real = launch_serve.get_config
+
+    def spy(arch, reduced=False):
+        asked.append(reduced)
+        return real(arch, reduced=True)
+
+    monkeypatch.setattr(launch_serve, "get_config", spy)
+    argv = ["--arch", "deepseek-7b", "--engine", "continuous",
+            "--batch", "1", "--prompt-len", "4", "--gen", "2",
+            "--page-size", "4", "--num-pages", "16"]
+    res = launch_serve.main(argv + ["--full"])
+    assert [len(r.tokens) for r in res.values()] == [2]
+    launch_serve.main(argv)
+    assert asked == [False, True]
+    assert "served 1 requests" in capsys.readouterr().out
