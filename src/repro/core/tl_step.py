@@ -105,15 +105,18 @@ def tl_loss_fn(model: Model, cfg: ModelConfig, remat_mode: str = "tl",
         # TL boundary for enc-dec: decoder block 0.  The encoder runs in the
         # node phase (it consumes node-local frontend embeddings).
         def loss(params, batch):
-            return model.loss(params, batch)[0]
+            with jax.named_scope("tl_loss"):
+                return model.loss(params, batch)[0]
         return loss
 
     permute_rows = (_make_row_permuter(mesh, reassembly)
                     if reassembly != "none" else None)
 
     def tail_fn(params, h1, tokens):
-        logits, h, aux = transformer.tail(params, cfg, h1, return_hidden=True)
-        return logits, h, aux
+        # scoped inside the checkpointed function, so the recompute carries
+        # the scope under ``rematted_computation``
+        with jax.named_scope("tl_tail"):
+            return transformer.tail(params, cfg, h1, return_hidden=True)
 
     if remat_mode == "tl":
         tail_exec = jax.checkpoint(
@@ -133,8 +136,9 @@ def tl_loss_fn(model: Model, cfg: ModelConfig, remat_mode: str = "tl",
         mask = batch.get("mask")
         extra = batch.get("embeds")
         # ---- node phase: first-layer activations X^(1)
-        h0 = transformer.embed_tokens(params, cfg, tokens, extra)
-        h1, aux0 = transformer.block0(params, cfg, h0)
+        with jax.named_scope("tl_node"):
+            h0 = transformer.embed_tokens(params, cfg, tokens, extra)
+            h1, aux0 = transformer.block0(params, cfg, h0)
         if permute_rows is not None:
             # ---- centralized-phase prologue: reassemble the node-major
             # virtual batch into shuffled batch order (shard-local perms,
@@ -149,21 +153,26 @@ def tl_loss_fn(model: Model, cfg: ModelConfig, remat_mode: str = "tl",
             # strategies give the same floats (on the TPU a generic scatter
             # fused into its consumers moved the loss by a few ulps)
             fence = jax.lax.optimization_barrier
-            rows = dict(zip(rows, fence(permute_rows(
-                batch["perm"], *fence(tuple(rows.values()))))))
+            with jax.named_scope("tl_reassembly"):
+                rows = dict(zip(rows, fence(permute_rows(
+                    batch["perm"], *fence(tuple(rows.values()))))))
             h1, targets = rows["h1"], rows["targets"]
             tokens = rows.get("tokens", tokens)
             mask = rows.get("mask", mask)
-        # ---- orchestrator phase: recompute-from-X^(1) BP
-        logits, h_final, aux = tail_exec(params, h1, tokens)
-        logits_txt = logits[:, F:] if F else logits
-        ce = cross_entropy(logits_txt, targets, mask)
-        total = ce + aux + aux0
-        if cfg.mtp_depth:
-            h_txt = h_final[:, F:] if F else h_final
-            mtp = transformer.mtp_logits(params, cfg, tokens, h_txt)
-            t2, valid = mtp_shift_targets(targets)
-            total = total + MTP_WEIGHT * cross_entropy(mtp, t2, valid)
+        # ---- orchestrator phase: recompute-from-X^(1) BP.  Scoped here as
+        # well: what depends on no input of the checkpointed function (the
+        # RoPE tables) is recomputed without the scope of its inside
+        with jax.named_scope("tl_tail"):
+            logits, h_final, aux = tail_exec(params, h1, tokens)
+        with jax.named_scope("tl_loss"):
+            logits_txt = logits[:, F:] if F else logits
+            ce = cross_entropy(logits_txt, targets, mask)
+            total = ce + aux + aux0
+            if cfg.mtp_depth:
+                h_txt = h_final[:, F:] if F else h_final
+                mtp = transformer.mtp_logits(params, cfg, tokens, h_txt)
+                t2, valid = mtp_shift_targets(targets)
+                total = total + MTP_WEIGHT * cross_entropy(mtp, t2, valid)
         return total
 
     return loss
@@ -199,7 +208,8 @@ def make_train_step(model: Model, cfg: ModelConfig, optimizer, *,
     if microbatch <= 1:
         def step(params, opt_state, batch):
             loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-            params, opt_state = optimizer.update(params, grads, opt_state)
+            with jax.named_scope("tl_optimizer"):
+                params, opt_state = optimizer.update(params, grads, opt_state)
             return params, opt_state, loss
         return step
 
@@ -220,7 +230,8 @@ def make_train_step(model: Model, cfg: ModelConfig, optimizer, *,
         (grads, loss_sum), _ = jax.lax.scan(body, (zeros, 0.0), micro)
         grads = jax.tree.map(lambda g, p: (g / microbatch).astype(p.dtype),
                              grads, params)
-        params, opt_state = optimizer.update(params, grads, opt_state)
+        with jax.named_scope("tl_optimizer"):
+            params, opt_state = optimizer.update(params, grads, opt_state)
         return params, opt_state, loss_sum / microbatch
 
     return step

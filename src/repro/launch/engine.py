@@ -85,8 +85,10 @@ detect/plan/restore/rejit/replay cost lands in ``Engine.recovery_log``
 """
 from __future__ import annotations
 
+import itertools
 import time
 from dataclasses import dataclass
+from functools import partial
 from typing import Any, Iterable, List, Optional
 
 import jax
@@ -105,7 +107,15 @@ from repro.launch.elastic import (HANG, DeviceFaultInjector, DeviceFaultSpec,
 @dataclass
 class EngineResult:
     """What one ``Engine.run`` produced.  ``losses`` is host-materialized
-    exactly once, at the end of the run."""
+    exactly once, at the end of the run.
+
+    ``input_wait_s`` (production mode) is the host seconds the step loop
+    spent waiting for its next device batch, the ``tl_input_wait`` spans of
+    the run: blocked on the prefetch queue with ``pipeline=True``, loading
+    and transferring the batch itself with ``pipeline=False``.  In elastic
+    mode it is the last pass's; in sim mode it stays 0.  ``launch.train``
+    prints it beside the run's wall time: a wait near the wall time means
+    the step is input-bound."""
     losses: np.ndarray
     steps: int
     wall_s: float
@@ -114,6 +124,7 @@ class EngineResult:
     stats: Optional[List] = None          # sim mode: flat StepStats list
     epoch_stats: Optional[List[List]] = None
     recovery: Optional[List] = None       # elastic mode: RecoveryReports
+    input_wait_s: float = 0.0
 
     @property
     def steps_per_s(self) -> float:
@@ -378,6 +389,7 @@ class Engine:
         return np.argsort(np.argsort(blocks, axis=1),
                           axis=1).reshape(-1).astype(np.int32)
 
+    @partial(jax.profiler.annotate_function, name="tl_put_batch")
     def _put_batch(self, host_batch):
         """host batch -> node-major device shards under tokens_pspec."""
         cfg, sh = self.cfg, self._batch_shardings
@@ -596,7 +608,14 @@ class Engine:
         self._pending_report = report
         return report
 
+    @partial(jax.profiler.annotate_function, name="tl_run")
     def _production_pass(self, loader, steps: int) -> EngineResult:
+        """One pass of the pjit step over ``loader``.  Host spans for the
+        profiler: ``tl_run`` (the pass), ``tl_step`` (each dispatch, with
+        its step number), ``tl_input_wait`` (waiting for the next batch),
+        ``tl_put_batch`` (one batch's perm and ``device_put``, on the
+        prefetch thread when pipelined) and ``tl_sync`` (the final wait
+        and the losses' transfer)."""
         step_fn = self._build_step()
         start = self._start_step
         if start >= steps:
@@ -634,6 +653,7 @@ class Engine:
             # strictly batch-serial oracle: the loader is not touched while
             # a step is in flight (the consumer blocks below)
             batches = map(self._put_batch, host_batches())
+        batches = iter(batches)
 
         # device scalars keyed by global step, one host sync at the end;
         # a replayed step simply overwrites its pre-rollback entry
@@ -649,23 +669,31 @@ class Engine:
         deadline = self.watchdog_s if (armed and self.watchdog_s
                                        and self.watchdog_s > 0) else None
         t0 = time.perf_counter()
+        input_wait = 0.0
         k = start
         try:
-            for k, batch in enumerate(batches, start=start):
+            for k in itertools.count(start):
+                t_wait = time.perf_counter()
+                with jax.profiler.TraceAnnotation("tl_input_wait"):
+                    batch = next(batches, None)
+                input_wait += time.perf_counter() - t_wait
+                if batch is None:
+                    break
                 self._maybe_inject(k)          # raises DeviceLost on verdict
                 t_step = time.perf_counter()
-                if deadline is not None and self._jit_warm:
-                    # supervised dispatch: a hung collective surfaces as a
-                    # WatchdogTimeout instead of stalling the run forever.
-                    # The warmup step (fresh jit: unbounded compile time)
-                    # runs unsupervised so a slow compile is never
-                    # misclassified as a hang.
-                    params, opt_state, loss = call_with_deadline(
-                        step_fn, (params, opt_state, batch),
-                        deadline_s=deadline, what=f"step {k}")
-                else:
-                    params, opt_state, loss = step_fn(params, opt_state,
-                                                      batch)
+                with jax.profiler.StepTraceAnnotation("tl_step", step_num=k):
+                    if deadline is not None and self._jit_warm:
+                        # supervised dispatch: a hung collective surfaces as
+                        # a WatchdogTimeout instead of stalling the run
+                        # forever.  The warmup step (fresh jit: unbounded
+                        # compile time) runs unsupervised so a slow compile
+                        # is never misclassified as a hang.
+                        params, opt_state, loss = call_with_deadline(
+                            step_fn, (params, opt_state, batch),
+                            deadline_s=deadline, what=f"step {k}")
+                    else:
+                        params, opt_state, loss = step_fn(params, opt_state,
+                                                          batch)
                 self._jit_warm = True
                 if self._pending_report is not None:
                     # first post-recovery step: its wall time is the re-jit
@@ -687,7 +715,6 @@ class Engine:
                     # state at the caller's chosen cadence (the prefetch
                     # queue keeps producing meanwhile)
                     self.save_ckpt(params, opt_state, k + 1)
-            jax.block_until_ready(params)
         except WatchdogTimeout as t:
             # a real (un-injected) stall: classify as a lost device with no
             # identified chip; the elastic loop (or the caller) decides what
@@ -700,13 +727,16 @@ class Engine:
             # on failure these may point at donated (deleted) buffers — a
             # later use then raises loudly instead of silently restarting
             self.params, self.opt_state = params, opt_state
-        wall = time.perf_counter() - t0
-        order = sorted(losses)
-        loss_arr = (np.asarray(jax.device_get([losses[i] for i in order]),
-                               np.float32)
-                    if order else np.zeros((0,), np.float32))
+        with jax.profiler.TraceAnnotation("tl_sync"):
+            jax.block_until_ready(params)
+            wall = time.perf_counter() - t0
+            order = sorted(losses)
+            loss_arr = (np.asarray(jax.device_get([losses[i] for i in order]),
+                                   np.float32)
+                        if order else np.zeros((0,), np.float32))
         return EngineResult(losses=loss_arr, steps=len(order), wall_s=wall,
-                            params=params, opt_state=opt_state)
+                            params=params, opt_state=opt_state,
+                            input_wait_s=input_wait)
 
     # ---------------------------------------------------------- sim facade
     def _run_sim(self, shards, epochs: int) -> EngineResult:

@@ -262,7 +262,8 @@ def main(argv=None):
     losses = result.losses.tolist()
     print(f"final loss {np.mean(losses[-5:]):.4f} "
           f"(start {np.mean(losses[:5]):.4f}) "
-          f"{result.steps_per_s:.2f} steps/s")
+          f"{result.steps_per_s:.2f} steps/s, "
+          f"input wait {result.input_wait_s:.3f} s of {result.wall_s:.3f} s")
     if args.ckpt:
         # same layout as the engine's step-boundary checkpoints, so a
         # --halt-at (or crashed-after-save) run's final checkpoint is

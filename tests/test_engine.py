@@ -18,6 +18,7 @@ leaks into other tests.
 """
 import json
 import os
+import re
 import subprocess
 import sys
 import textwrap
@@ -232,6 +233,7 @@ def test_train_cli_smoke():
         env=_ENV_BASE, capture_output=True, text=True, timeout=560)
     assert proc.returncode == 0, proc.stderr[-3000:]
     assert "final loss" in proc.stdout
+    assert re.search(r"input wait \d+\.\d{3} s of \d+\.\d{3} s", proc.stdout)
     assert "mesh=debug(2, 2)" in proc.stdout     # 8 forced devices -> (2,2)
 
 
@@ -375,3 +377,73 @@ def test_engine_step_compiles_once():
         jax.monitoring.unregister_event_duration_listener(on_event)
     assert res.steps == 3
     assert compiles == []
+
+
+@pytest.mark.parametrize("pipeline", [True, False],
+                         ids=["pipelined", "serial"])
+def test_engine_host_spans(tmp_path, pipeline):
+    """A profiled 3-step production run carries the engine's host spans,
+    nested as ``Engine._production_pass`` documents them, in the compact
+    trace the benchmark reads; the input-wait counter lies within the
+    run; and the spans change no arithmetic: the profiled run's losses
+    equal an unprofiled run's on the other path, bit for bit."""
+    import jax
+
+    from bench.lib import scopes
+    from repro.configs import get_config
+    from repro.configs.base import InputShape
+    from repro.data.pipeline import (VirtualBatchLoader, shard_corpus,
+                                     synthetic_corpus)
+    from repro.launch.engine import Engine
+    from repro.launch.mesh import make_debug_mesh
+    from repro.models import build_model
+    from repro.optim import adamw
+
+    cfg = get_config("deepseek-7b", reduced=True)
+    model = build_model(cfg)
+    docs = synthetic_corpus(4 * 16, 16, cfg.vocab_size, seed=1)
+
+    def run(pipe):
+        eng = Engine(model, cfg, adamw(3e-3), make_debug_mesh(1, 1),
+                     InputShape("t", 16, 8, "train"), pipeline=pipe,
+                     reassembly="pallas")
+        eng.init(jax.random.PRNGKey(0))
+        loader = VirtualBatchLoader(shard_corpus(docs, 4), 8, seed=0)
+        return eng, loader
+
+    eng, loader = run(pipeline)
+    eng.run(loader, steps=1)               # compile outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        res = eng.run(loader, steps=3)
+    finally:
+        jax.profiler.stop_trace()
+    assert res.steps == 3
+    assert 0 <= res.input_wait_s <= res.wall_s
+
+    spans = {}
+    for nm, s, d in scopes.extract(str(tmp_path))["host"]:
+        if nm.startswith("tl_"):
+            spans.setdefault(nm, []).append((s, s + d))
+    (run_lo, run_hi), = spans["tl_run"]
+    assert len(spans["tl_step"]) == 3
+    assert len(spans["tl_put_batch"]) == 3
+    assert len(spans["tl_input_wait"]) == 4    # three batches, then the end
+    assert len(spans["tl_sync"]) == 1
+    for nm, iv in spans.items():
+        assert all(run_lo <= s and e <= run_hi for s, e in iv), nm
+    # the loop waits for a batch, then dispatches it; the sync comes last
+    waits, steps = sorted(spans["tl_input_wait"]), sorted(spans["tl_step"])
+    for (_, w_end), (s_lo, s_hi), (w_next, _) in zip(waits, steps,
+                                                      waits[1:]):
+        assert w_end <= s_lo and s_hi <= w_next
+    assert steps[-1][1] <= spans["tl_sync"][0][0]
+    if not pipeline:
+        # the serial path loads and puts each batch inside its wait
+        for s, e in spans["tl_put_batch"]:
+            assert any(a <= s and e <= b for a, b in waits)
+
+    other, other_loader = run(not pipeline)
+    other.run(other_loader, steps=1)
+    np.testing.assert_array_equal(res.losses,
+                                  other.run(other_loader, steps=3).losses)

@@ -11,6 +11,8 @@ The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and every test worker
 imports this file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -119,3 +121,95 @@ def test_act_compress_compiles(one_chip, codec):
         return decompress(payload, x.shape, interpret=False)
 
     _compiles_with_kernel(roundtrip, x)
+
+
+# Instructions that move or name data and compute nothing; the scope check
+# below leaves them out.  Instructions the compiler adds on its own (layout
+# iotas and pads, ConcatBitcast of sliced stacks) carry no op_name at all.
+HLO_EXEMPT = {"parameter", "constant", "tuple", "get-tuple-element",
+              "bitcast", "copy", "copy-start", "copy-done", "slice-start",
+              "slice-done"}
+TL_SCOPES = {"tl_node", "tl_reassembly", "tl_tail", "tl_loss",
+             "tl_optimizer"}
+
+
+def _device_ops(text):
+    """(instruction line, opcode, op_name) of every instruction the device
+    runs as an op of its own: those of the entry computation and of the
+    computations its control flow calls, not of fused or applied ones."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None and line.strip().startswith(("%", "ROOT")):
+            cur.append(line.strip())
+    inner = {c for lines in comps.values() for ln in lines
+             for c in re.findall(r"(?:calls|to_apply)=%([\w.\-]+)", ln)}
+    out = []
+    for c, lines in comps.items():
+        if c in inner:
+            continue
+        for ln in lines:
+            rest = ln.split(" = ", 1)[1]
+            if rest.startswith("("):          # a tuple shape: skip it whole
+                depth = 0
+                for i, ch in enumerate(rest):
+                    depth += (ch == "(") - (ch == ")")
+                    if depth == 0:
+                        break
+                rest = rest[i + 1:]
+            else:
+                rest = rest.split(" ", 1)[1]
+            opcode = re.match(r"\s*([\w\-]+)", rest).group(1)
+            name = re.search(r'op_name="([^"]*)"', ln)
+            out.append((ln, opcode, name.group(1) if name else None))
+    return out
+
+
+def test_tl_step_phases_are_scoped(one_chip, monkeypatch):
+    """The production TL step, compiled at small widths with the Pallas
+    reassembly, names its phases for the benchmark's trace reduction:
+    every op the program traced carries exactly one TL scope, the
+    reassembly kernel's custom calls still match the reader that finds
+    them, and the tail appears both recomputed and transposed."""
+    from bench.lib import spec
+    from bench.lib.scopes import PHASE_RE, phase_of
+    from bench.lib.trace import op_name
+    from repro.configs import get_config
+    from repro.core.tl_step import make_train_step
+    from repro.models import build_model
+    from repro.optim import adamw
+
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    cfg = get_config("deepseek-7b", reduced=True)
+    model, opt = build_model(cfg), adamw(3e-3)
+    step = make_train_step(model, cfg, opt, reassembly="pallas")
+    S = _spec(one_chip)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    state = jax.eval_shape(opt.init, params)
+    B, T = 8, 128
+    batch = {"tokens": S((B, T), jnp.int32), "targets": S((B, T), jnp.int32),
+             "perm": S((B,), jnp.int32)}
+    text = jax.jit(step, donate_argnums=(0, 1)).lower(
+        jax.tree.map(lambda a: S(a.shape, a.dtype), params),
+        jax.tree.map(lambda a: S(a.shape, a.dtype), state),
+        batch).compile().as_text()
+
+    ops = _device_ops(text)
+    seen = set()
+    for line, opcode, path in ops:
+        if opcode in HLO_EXEMPT or path is None:
+            continue
+        scopes = set(PHASE_RE.findall(path))
+        assert len(scopes) == 1 and scopes <= TL_SCOPES, line[:300]
+        seen.add(phase_of(path))
+    assert {p for p, _ in seen} == TL_SCOPES
+    assert {("tl_tail", "recompute"), ("tl_tail", "bwd")} <= seen
+
+    pattern = spec.load_module("vb_scatter_roofline").KERNELS["vb_scatter"][0]
+    calls = [op_name(line) for line, _, _ in ops if "tpu_custom_call" in line]
+    assert len(calls) == 2, calls      # the forward scatter, its transpose
+    assert all(re.search(pattern, c) for c in calls), calls
