@@ -47,11 +47,40 @@ def swiglu_init(key, d: int, d_ff: int, dtype=jnp.float32):
     }
 
 
+@jax.custom_vjp
+def swiglu_act(g, u):
+    """``silu(g) * u``, computed once in its own elementwise pass.
+
+    Left to autodiff, XLA fuses this activation (forward) and its gradient
+    (backward) into the prologue of the matmul that consumes it, which
+    re-evaluates the ``exp`` and re-reads ``g`` and ``u`` for every output
+    tile.  The optimization barriers on the values crossing into and out of
+    the matmuls keep the elementwise work out of every matmul fusion.
+    """
+    return _swiglu_act_fwd(g, u)[0]
+
+
+def _swiglu_act_fwd(g, u):
+    g_in, u_in = jax.lax.optimization_barrier((g, u))
+    h = jax.nn.silu(g_in) * u_in
+    return jax.lax.optimization_barrier(h), (g, u)
+
+
+def _swiglu_act_bwd(res, dh):
+    g, u, dh = jax.lax.optimization_barrier((*res, dh))
+    s = jax.nn.sigmoid(g)
+    dg = dh * u * s * (1 + g * (1 - s))
+    du = dh * g * s
+    return jax.lax.optimization_barrier((dg, du))
+
+
+swiglu_act.defvjp(_swiglu_act_fwd, _swiglu_act_bwd)
+
+
 def swiglu(params, x):
     g = jnp.einsum("...d,df->...f", x, params["w_gate"])
     u = jnp.einsum("...d,df->...f", x, params["w_up"])
-    h = jax.nn.silu(g) * u
-    return jnp.einsum("...f,fd->...d", h, params["w_down"])
+    return jnp.einsum("...f,fd->...d", swiglu_act(g, u), params["w_down"])
 
 
 # ---------------------------------------------------------------------- RoPE

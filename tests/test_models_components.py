@@ -4,6 +4,7 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.analysis.hlo_flops import _shape_elems_bytes
@@ -12,7 +13,8 @@ from repro.configs import get_config
 from repro.models import moe as moe_mod
 from repro.models.attention import attend_blockwise, attend_dense
 from repro.models.layers import apply_mrope, apply_rope, causal_conv1d, \
-    causal_conv1d_init, causal_conv1d_step, rmsnorm, rmsnorm_init
+    causal_conv1d_init, causal_conv1d_step, rmsnorm, rmsnorm_init, swiglu, \
+    swiglu_init
 
 
 def test_blockwise_attention_equals_dense():
@@ -83,6 +85,43 @@ def test_rmsnorm_scale_invariance():
     y1 = rmsnorm(p, x)
     y2 = rmsnorm(p, x * 7.3)
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), atol=1e-5)
+
+
+# ---------------------------------------------------------------- SwiGLU
+
+def _plain_swiglu(params, x):
+    g, u = x @ params["w_gate"], x @ params["w_up"]
+    return (jax.nn.silu(g) * u) @ params["w_down"]
+
+
+@pytest.mark.parametrize("case", ["train", "decode", "remat"])
+def test_swiglu_matches_the_plain_expression(case):
+    """The fenced activation with its hand-written gradient computes what
+    autodiff of ``silu(x Wg) * (x Wu) Wd`` computes: the value and the
+    gradients of x and of the three weights, in f32."""
+    kp, kx, kc = jax.random.split(jax.random.PRNGKey(11), 3)
+    d, d_ff = 32, 88
+    lead = (1, 1) if case == "decode" else (2, 24)
+    params = swiglu_init(kp, d, d_ff)
+    x = jax.random.normal(kx, (*lead, d)) * 2.0
+    cot = jax.random.normal(kc, (*lead, d))
+    fn = swiglu
+    if case == "remat":
+        fn = jax.checkpoint(
+            swiglu, policy=jax.checkpoint_policies.nothing_saveable)
+
+    def loss(f):
+        return lambda p, x: jnp.sum(f(p, x) * cot)
+
+    np.testing.assert_allclose(np.asarray(fn(params, x)),
+                               np.asarray(_plain_swiglu(params, x)),
+                               rtol=1e-6, atol=1e-6)
+    got = jax.grad(loss(fn), argnums=(0, 1))(params, x)
+    want = jax.grad(loss(_plain_swiglu), argnums=(0, 1))(params, x)
+    jax.tree_util.tree_map_with_path(
+        lambda path, g, w: np.testing.assert_allclose(
+            np.asarray(g), np.asarray(w), rtol=1e-5, atol=1e-5,
+            err_msg=jax.tree_util.keystr(path)), got, want)
 
 
 # ------------------------------------------------------------------- MoE

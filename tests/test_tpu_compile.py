@@ -133,10 +133,8 @@ TL_SCOPES = {"tl_node", "tl_reassembly", "tl_tail", "tl_loss",
              "tl_optimizer"}
 
 
-def _device_ops(text):
-    """(instruction line, opcode, op_name) of every instruction the device
-    runs as an op of its own: those of the entry computation and of the
-    computations its control flow calls, not of fused or applied ones."""
+def _computations(text):
+    """{computation name: its instruction lines} of an HLO module's text."""
     comps, cur = {}, None
     for line in text.splitlines():
         head = re.match(r"^(?:ENTRY )?%([\w.\-]+) .*\{$", line)
@@ -146,8 +144,20 @@ def _device_ops(text):
             cur = None
         elif cur is not None and line.strip().startswith(("%", "ROOT")):
             cur.append(line.strip())
+    return comps
+
+
+def _called(line):
+    return re.findall(r"(?:calls|to_apply)=%([\w.\-]+)", line)
+
+
+def _device_ops(text):
+    """(instruction line, opcode, op_name) of every instruction the device
+    runs as an op of its own: those of the entry computation and of the
+    computations its control flow calls, not of fused or applied ones."""
+    comps = _computations(text)
     inner = {c for lines in comps.values() for ln in lines
-             for c in re.findall(r"(?:calls|to_apply)=%([\w.\-]+)", ln)}
+             for c in _called(ln)}
     out = []
     for c, lines in comps.items():
         if c in inner:
@@ -213,3 +223,52 @@ def test_tl_step_phases_are_scoped(one_chip, monkeypatch):
     calls = [op_name(line) for line, _, _ in ops if "tpu_custom_call" in line]
     assert len(calls) == 2, calls      # the forward scatter, its transpose
     assert all(re.search(pattern, c) for c in calls), calls
+
+
+def _matmul_fusions_with_exp(text):
+    """Fused computations that hold a convolution (the TPU's matmul) and,
+    in themselves or in what they call, an exponential."""
+    comps = _computations(text)
+
+    def body(name, seen):
+        if name not in seen:
+            seen.add(name)
+            for ln in comps[name]:
+                for c in _called(ln):
+                    body(c, seen)
+        return seen
+
+    return [name for name, lines in comps.items()
+            if any(" convolution(" in ln for ln in lines)
+            and any(" exponential(" in ln for c in body(name, set())
+                    for ln in comps[c])]
+
+
+def test_swiglu_keeps_its_activation_out_of_the_matmuls(one_chip):
+    """``grad`` of RMSNorm -> SwiGLU -> residual at deepseek-7b's MLP widths:
+    no matmul fusion re-evaluates silu or its derivative in its prologue.
+    The same graph written as the plain expression does, so the check is
+    live."""
+    from repro.models.layers import rmsnorm, swiglu
+
+    def plain(params, x):
+        g, u = x @ params["w_gate"], x @ params["w_up"]
+        return (jax.nn.silu(g) * u) @ params["w_down"]
+
+    def grad_of(mlp):
+        def loss(p, x):
+            y = x + mlp(p["ffn"], rmsnorm(p["norm"], x))
+            return jnp.sum(y * y)
+        return jax.grad(loss)
+
+    S = _spec(one_chip)
+    d, d_ff = 4096, 11008
+    params = {"norm": {"scale": S((d,))},
+              "ffn": {"w_gate": S((d, d_ff)), "w_up": S((d, d_ff)),
+                      "w_down": S((d_ff, d))}}
+    x = S((2, 128, d))
+    texts = {name: jax.jit(grad_of(mlp)).lower(params, x).compile().as_text()
+             for name, mlp in (("plain", plain), ("swiglu", swiglu))}
+    assert _matmul_fusions_with_exp(texts["plain"])
+    assert " convolution(" in texts["swiglu"]
+    assert _matmul_fusions_with_exp(texts["swiglu"]) == []
