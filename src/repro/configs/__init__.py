@@ -16,6 +16,7 @@ from repro.configs import (
     seamless_m4t_medium,
     qwen2_vl_72b,
     deepseek_7b,
+    deepseek_67b,
     mamba2_780m,
 )
 
@@ -31,6 +32,7 @@ ARCHS = {
         seamless_m4t_medium,
         qwen2_vl_72b,
         deepseek_7b,
+        deepseek_67b,
         mamba2_780m,
     )
 }

@@ -1,6 +1,6 @@
 """Per-assigned-architecture smoke tests (reduced configs, CPU).
 
-For each of the 10 architectures: instantiate the reduced same-family
+For each registered architecture: instantiate the reduced same-family
 variant, run one forward and one TL train step, assert output shapes and
 finiteness; run decode and check it matches the full forward.
 """

@@ -272,3 +272,138 @@ def test_swiglu_keeps_its_activation_out_of_the_matmuls(one_chip):
     assert _matmul_fusions_with_exp(texts["plain"])
     assert " convolution(" in texts["swiglu"]
     assert _matmul_fusions_with_exp(texts["swiglu"]) == []
+
+
+def _collectives(text):
+    """(instruction line, result shapes, op_name) of every device op that
+    exchanges data between chips: a collective instruction, or a fusion
+    that holds one (the TPU compiler's async and reduce-scatter fusions).
+    The shapes are those of the collectives, where a fusion holds them."""
+    comps = _computations(text)
+    found = re.compile(r"= (\S+) (all-gather|all-reduce|reduce-scatter|"
+                       r"collective-permute|all-to-all)(?:-start|-done)?\(")
+
+    def inside(name, seen):
+        if name in seen:
+            return []
+        seen.add(name)
+        out = []
+        for ln in comps.get(name, ()):
+            m = found.search(ln)
+            if m:
+                out.append((m.group(2), m.group(1)))
+            for c in _called(ln):
+                out += inside(c, seen)
+        return out
+
+    out = []
+    for line, _, path in _device_ops(text):
+        m = found.search(line)
+        held = [(m.group(2), m.group(1))] if m else []
+        for c in _called(line):
+            held += inside(c, set())
+        if held:
+            out.append((line, held, path))
+    return out
+
+
+def _scope_paths(text):
+    """(upstream, downstream): functions from an instruction's name to the
+    op_names it stands for.  Its own, where it has one; else, for one the
+    compiler added with none (the start of an async collective, a permute
+    that realigns a reduce-scatter's rows, and the slices and tuple reads
+    around them), those of the nearest named instructions it reads from
+    (upstream) or that read from it (downstream)."""
+    rest, users = {}, {}
+    for body in _computations(text).values():
+        for ln in body:
+            name, _, r = ln.removeprefix("ROOT ").partition(" = ")
+            name = name.lstrip("%")
+            rest[name] = r
+            # the operands: the %names before the first attribute
+            for arg in set(re.findall(r"%([\w.\-]+)", r.split("), ", 1)[0])):
+                users.setdefault(arg, []).append(name)
+    operands = {n: re.findall(r"%([\w.\-]+)", r.split("), ", 1)[0])
+                for n, r in rest.items()}
+
+    def walk(edges):
+        memo = {}
+
+        def paths(name, seen=frozenset()):
+            if name not in memo:
+                own = re.search(r'op_name="([^"]*)"', rest.get(name, ""))
+                memo[name] = {own.group(1)} if own else set().union(*(
+                    paths(n, seen | {name}) for n in edges.get(name, ())
+                    if n not in seen))
+            return memo[name]
+        return paths
+    return walk(operands), walk(users)
+
+
+def test_ds67b_stage_fits_keeps_gqa_local_and_scopes_collectives(topo):
+    """A 2-layer stage of DeepSeek LLM 67B at its published widths (64 query
+    heads over 8 KV heads, d_ff 22 016), vocabulary cut to 12 800, trained
+    as the engine jits its step: global batch 16 x 1024, the Pallas
+    reassembly, AdamW, on a (2, 2) data x model mesh of a described v5e
+    host.  It fits a chip (arguments and temporaries under 15 GiB of 16),
+    no all-gather or all-to-all moves q, k or v (each KV head's 8 query
+    heads stay on the chip that holds it), and every collective carries the
+    scope of one TL phase: its own op_name's, or, where the compiler added
+    it with none, that of the data it moves."""
+    import dataclasses
+    import numpy as np
+    from jax.sharding import Mesh
+    from bench.lib.scopes import PHASE_RE
+    from repro.configs import get_config
+    from repro.configs.base import InputShape
+    from repro.core.tl_step import make_train_step, train_shardings
+    from repro.models import build_model
+    from repro.optim import adamw
+
+    cfg = dataclasses.replace(get_config("deepseek-67b"), n_layers=2,
+                              vocab_size=12800)
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
+    model = build_model(cfg)
+    opt = adamw(3.2e-4, weight_decay=0.1, b1=0.9, b2=0.95, eps=1e-8)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    state = jax.eval_shape(opt.init, params)
+    B, S = 16, 1024
+    with mesh:
+        in_sh, out_sh = train_shardings(params, state, cfg, mesh,
+                                        InputShape("ds67b", S, B, "train"),
+                                        with_perm=True)
+    shapes = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        (params, state), in_sh[:2])
+    batch = {"tokens": jax.ShapeDtypeStruct((B, S), jnp.int32),
+             "targets": jax.ShapeDtypeStruct((B, S), jnp.int32),
+             "perm": jax.ShapeDtypeStruct((B,), jnp.int32)}
+    step = make_train_step(model, cfg, opt, reassembly="pallas", mesh=mesh)
+    compiled = jax.jit(step, in_shardings=in_sh, out_shardings=out_sh,
+                       donate_argnums=(0, 1)).lower(*shapes, batch).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15 * 2 ** 30
+
+    text = compiled.as_text()
+    colls = _collectives(text)
+    upstream, downstream = _scope_paths(text)
+    kinds = {k for _, held, _ in colls for k, _ in held}
+    assert {"all-gather", "all-reduce"} <= kinds, kinds
+    hd = cfg.resolved_head_dim
+    qkv = re.compile(rf"\[\d+,{S},\d+,{hd}\]")      # (rows, S, heads, hd)
+    for line, held, path in colls:
+        for kind, shape in held:
+            assert not (kind in ("all-gather", "all-to-all")
+                        and qkv.search(shape)), line[:300]
+        # what the compiler adds on its own (the start of an async
+        # collective, a permute that realigns a reduce-scatter's rows) has
+        # no op_name: it takes the scope of the phase whose data it moves,
+        # or, for a weight gathered ahead of its phase, of the phase that
+        # reads what it gathered
+        name = line.split(" = ", 1)[0].lstrip("%")
+        scopes = set(PHASE_RE.findall(path)) if path is not None else set()
+        for paths in (upstream, downstream):
+            if path is None and not scopes:
+                scopes = {s for pt in paths(name)
+                          for s in PHASE_RE.findall(pt)}
+        assert len(scopes) == 1 and scopes <= TL_SCOPES, line[:300]
